@@ -18,6 +18,8 @@ def main() -> None:
                          "have a perf trajectory to compare against")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (common, fig4_throughput, fig6_overheads,
                             fig7_10_parallel, fig11_pareto, fig12_cpu_accel,
                             fig13_endtoend, fig14_capacity, fig15_trace,

@@ -17,6 +17,7 @@ Run:  PYTHONPATH=src python examples/async_serving.py
 """
 import time
 
+from repro.compile_cache import enable_compile_cache
 from repro.serve import (OpenLoopGen, ClosedLoopGen, ServeConfig, SimServer,
                          SyntheticWorkload, build, serve, sim_requests)
 
@@ -100,6 +101,7 @@ def main(smoke: bool = False):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     import argparse
 
     ap = argparse.ArgumentParser(description=__doc__)

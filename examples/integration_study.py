@@ -5,6 +5,7 @@ tables (Tables 2-3) — printed as a single report.
 
 Run:  PYTHONPATH=src python examples/integration_study.py
 """
+from repro.compile_cache import enable_compile_cache
 from benchmarks import (fig4_throughput, fig6_overheads, fig7_10_parallel,
                         fig11_pareto, fig12_cpu_accel, table2_3_cost)
 
@@ -26,4 +27,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -4,6 +4,7 @@ Run:  PYTHONPATH=src python examples/quickstart.py
 """
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (ErbiumEngine, compile_rules, generate_queries,
                         generate_rules)
 from repro.core.encoder import queries_to_arrays
@@ -43,4 +44,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

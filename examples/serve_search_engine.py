@@ -13,6 +13,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.aggregator import batch_stats, paper_policy
 from repro.core.compiler import compile_rules
 from repro.core.engine import ErbiumEngine
@@ -74,4 +75,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -9,6 +9,7 @@ Run:  PYTHONPATH=src python examples/train_lm.py --steps 200
 import argparse
 import dataclasses
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import get_config
 from repro.ft.failures import FailureInjector
 from repro.train.loop import TrainConfig, fit
@@ -48,4 +49,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -90,10 +90,10 @@ def device_table(table, tile_r: int = 512, partitioned: bool = False,
 
 
 @functools.partial(jax.jit, static_argnames=("tile_b", "tile_r", "backend",
-                                             "n_engines", "interpret"))
+                                             "n_engines"))
 def match_rules(queries, dt: DeviceRuleTable, *, tile_b: int = 256,
                 tile_r: int = 512, backend: str = "pallas",
-                n_engines: int = 1, interpret: bool = True):
+                n_engines: int = 1):
     """queries: (B, C) int32. Returns (decision, weight, rule_id) (B,) each.
 
     n_engines splits the batch into parallel kernel lanes (the paper's
@@ -111,15 +111,14 @@ def match_rules(queries, dt: DeviceRuleTable, *, tile_b: int = 256,
         if n_engines > 1:
             lanes = qt.reshape(C, n_engines, Bp // n_engines).swapaxes(0, 1)
             fn = functools.partial(rule_match_pallas, tile_b=tile_b,
-                                   tile_r=tile_r, interpret=interpret)
+                                   tile_r=tile_r)
             bw, bi = jax.vmap(lambda q: fn(q, dt.mins_t, dt.maxs_t,
                                            dt.weights))(lanes)
             w = bw.reshape(Bp)
             idx = bi.reshape(Bp)
         else:
             bw, bi = rule_match_pallas(qt, dt.mins_t, dt.maxs_t, dt.weights,
-                                       tile_b=tile_b, tile_r=tile_r,
-                                       interpret=interpret)
+                                       tile_b=tile_b, tile_r=tile_r)
             w, idx = bw[0], bi[0]
 
     w, idx = w[:B], idx[:B]

@@ -52,12 +52,17 @@ def _kernel(q_ref, mn_ref, mx_ref, w_ref, bw_ref, bi_ref, *, n_crit: int,
 
 def rule_match_pallas(queries_t, mins_t, maxs_t, weights,
                       *, tile_b: int = 256, tile_r: int = 512,
-                      interpret: bool = True):
+                      interpret: bool | None = None):
     """queries_t: (C, B) int32; mins_t/maxs_t: (C, R); weights: (1, R).
 
     B % tile_b == 0 and R % tile_r == 0 (ops.py pads).
     Returns (best_w (1, B), best_i (1, B)).
+
+    ``interpret=None`` compiles the kernel on a TPU and runs the Pallas
+    interpreter on any other backend.
     """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     C, B = queries_t.shape
     R = mins_t.shape[1]
     assert B % tile_b == 0 and R % tile_r == 0, (B, R, tile_b, tile_r)

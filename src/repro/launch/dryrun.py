@@ -29,7 +29,7 @@ import numpy as np
 from repro.configs.base import (ASSIGNED_ARCHS, SHAPES_BY_NAME, ShapeCell,
                                 get_config)
 from repro.launch import hlo_analysis
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import auto_axes, make_production_mesh
 from repro.launch.steps import (cell_batch_struct, jit_decode, jit_prefill,
                                 jit_train_step, make_ctx, microbatches_for)
 from repro.models.registry import build_model
@@ -91,7 +91,7 @@ def _mesh_for(multi_pod: bool):
     if spec:  # tests: e.g. "2x2" or "2x2x2"
         dims = tuple(int(d) for d in spec.split("x"))
         axes = ("pod", "data", "model")[-len(dims):]
-        return jax.make_mesh(dims, axes)
+        return jax.make_mesh(dims, axes, axis_types=auto_axes(len(dims)))
     return make_production_mesh(multi_pod=multi_pod)
 
 

@@ -8,12 +8,19 @@ from __future__ import annotations
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
+
+
+def auto_axes(n: int) -> tuple:
+    # jax >= 0.9 makes Explicit axes by default; the sharding rules here
+    # constrain activations with_sharding_constraint, which needs Auto axes
+    return (AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=auto_axes(len(axes)))
 
 
 def make_mesh_for(n_devices: int, model_parallel: int = 0):
@@ -25,7 +32,7 @@ def make_mesh_for(n_devices: int, model_parallel: int = 0):
                 model_parallel = cand
                 break
     return jax.make_mesh((n_devices // model_parallel, model_parallel),
-                         ("data", "model"))
+                         ("data", "model"), axis_types=auto_axes(2))
 
 
 def batch_axes_of(mesh) -> tuple:

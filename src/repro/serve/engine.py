@@ -135,7 +135,8 @@ class LMServer:
             return []
         toks, plens, max_new = pb.toks, pb.plens, pb.max_new
         if self.rule_filter is not None and pb.mct_encoded is not None:
-            keep = self._mct_feasible(rs, pb.mct_encoded, pb.mct_owner)
+            keep = self._mct_feasible(rs, pb.mct_encoded, pb.mct_owner,
+                                      device=device)
             if not all(keep):
                 # slice the already-prepared rows — no host re-encode on
                 # the device thread's critical path
@@ -199,35 +200,37 @@ class LMServer:
                 [toks, np.zeros((Bp - B, toks.shape[1]), np.int32)])
 
         params = self._params_on(device)
-        cache = self.model.init_cache(Bp, total)
-        if device is not None:
-            cache = jax.device_put(cache, device)
-        # prefill via the decode path, token by token up to each prompt len
-        # (keeps one compiled step; a fused prefill kernel is the fast path
-        # for attention archs and is exercised in tests via model.prefill)
-        generated = [[] for _ in range(B)]
-        last_logits = None
-        for pos in range(max_p):
-            step_tok = jnp.asarray(toks[:, pos:pos + 1])
-            last_logits, cache = self._decode(params, cache, step_tok,
-                                              jnp.int32(pos))
-        t1 = time.perf_counter()
+        # the cache, step tokens and argmax live on the replica's device
+        with jax.default_device(device):
+            cache = self.model.init_cache(Bp, total)
+            # prefill via the decode path, token by token up to each prompt
+            # len (keeps one compiled step; a fused prefill kernel is the
+            # fast path for attention archs, exercised in tests via
+            # model.prefill)
+            generated = [[] for _ in range(B)]
+            last_logits = None
+            for pos in range(max_p):
+                step_tok = jnp.asarray(toks[:, pos:pos + 1])
+                last_logits, cache = self._decode(params, cache, step_tok,
+                                                  jnp.int32(pos))
+            t1 = time.perf_counter()
 
-        cur = np.asarray(jnp.argmax(last_logits[:, -1], axis=-1),
-                         np.int32)
-        for s in range(max_new):
-            for i in range(B):
-                if s < rs[i].max_new_tokens:
-                    generated[i].append(int(cur[i]))
-            pos = max_p + s
-            if pos >= total - 1 or s == max_new - 1:
-                break
-            logits, cache = self._decode(params, cache,
-                                         jnp.asarray(cur[:, None]),
-                                         jnp.int32(pos))
-            cur = np.asarray(jnp.argmax(logits[:, -1], axis=-1), np.int32)
-        jax.block_until_ready(cur)
-        t2 = time.perf_counter()
+            cur = np.asarray(jnp.argmax(last_logits[:, -1], axis=-1),
+                             np.int32)
+            for s in range(max_new):
+                for i in range(B):
+                    if s < rs[i].max_new_tokens:
+                        generated[i].append(int(cur[i]))
+                pos = max_p + s
+                if pos >= total - 1 or s == max_new - 1:
+                    break
+                logits, cache = self._decode(params, cache,
+                                             jnp.asarray(cur[:, None]),
+                                             jnp.int32(pos))
+                cur = np.asarray(jnp.argmax(logits[:, -1], axis=-1),
+                                 np.int32)
+            jax.block_until_ready(cur)
+            t2 = time.perf_counter()
 
         return [Completion(rid=r.rid, tokens=np.asarray(g, np.int32),
                            prefill_ms=(t1 - t0) * 1e3,
@@ -245,12 +248,12 @@ class LMServer:
                                  deadline=deadline)
 
     def _mct_feasible(self, rs: List[Request], encoded: np.ndarray,
-                      owner: List[int]) -> List[bool]:
+                      owner: List[int], device=None) -> List[bool]:
         """MCT filtering stage: all connection queries of the batch were
         encoded host-side into ONE kernel input (the paper's aggregation
-        lesson); match on device, then drop requests with an infeasible
-        connection (connect time < MCT)."""
-        dec, _, _ = self.rule_filter.match(encoded)
+        lesson); match on ``device``, then drop requests with an
+        infeasible connection (connect time < MCT)."""
+        dec, _, _ = self.rule_filter.match(encoded, device=device)
         dec = np.asarray(dec)
         feasible = [True] * len(rs)
         pos = {i: 0 for i in range(len(rs))}

@@ -8,7 +8,7 @@ from pathlib import Path
 import pytest
 
 
-def _run(args, env_extra, cwd="/root/repo"):
+def _run(args, env_extra, cwd=Path(__file__).resolve().parents[1]):
     import os
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"

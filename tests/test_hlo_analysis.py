@@ -1,5 +1,7 @@
 """HLO static analyzer: cross-validation vs XLA cost_analysis and analytic
 FLOP counts; while-loop trip-count multiplication; collective extraction."""
+from pathlib import Path
+
 import numpy as np
 import pytest
 
@@ -76,10 +78,11 @@ def test_collectives_extracted(monkeypatch):
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, json
-        from jax.sharding import PartitionSpec as P, NamedSharding
+        from jax.sharding import AxisType, PartitionSpec as P, NamedSharding
         import sys; sys.path.insert(0, "src")
         from repro.launch.hlo_analysis import analyze_text
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         def f(x, w):
             y = x @ w
             return jax.lax.with_sharding_constraint(
@@ -96,7 +99,7 @@ def test_collectives_extracted(monkeypatch):
                           "np": r["num_partitions"]}))
     """)
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True, cwd="/root/repo")
+                         text=True, cwd=str(Path(__file__).resolve().parents[1]))
     assert out.returncode == 0, out.stderr[-2000:]
     res = json.loads(out.stdout.strip().splitlines()[-1])
     assert res["np"] == 8

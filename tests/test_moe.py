@@ -5,6 +5,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 from repro.configs.base import MoEConfig
 from repro.models import moe as moe_mod
@@ -19,7 +20,8 @@ def test_moe_forward_matches_ref(mode):
                               jnp.float32)
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.standard_normal((2, 6, D)), jnp.float32)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     out = moe_mod.moe_forward(params, x, cfg=cfg, act="swiglu", mesh=mesh,
                               batch_axes=("data",))
     ref = moe_mod.moe_ref(params, x, cfg=cfg, act="swiglu")
@@ -35,7 +37,8 @@ def test_moe_capacity_drops_tokens_gracefully():
                               jnp.float32)
     rng = np.random.default_rng(1)
     x = jnp.asarray(rng.standard_normal((1, 32, D)), jnp.float32)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     out = moe_mod.moe_forward(params, x, cfg=cfg, act="gelu", mesh=mesh,
                               batch_axes=("data",))
     assert bool(jnp.isfinite(out).all())

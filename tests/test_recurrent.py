@@ -4,6 +4,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 from repro.configs.base import SSMConfig
 from repro.models import ssm as ssm_mod
@@ -74,7 +75,8 @@ def test_slstm_local_grad_matches_plain():
     params = xlstm_mod.init_slstm(jax.random.PRNGKey(1), D, H, jnp.float32)
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.standard_normal((B, T, D)) * 0.5, jnp.float32)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
     def loss_plain(p, x):
         return jnp.sum(xlstm_mod.slstm_forward(p, x, n_heads=H) ** 2)

@@ -237,6 +237,53 @@ def test_mesh_replicas_bit_identical_on_two_devices():
         np.testing.assert_array_equal(by_sync[c.rid].tokens, c.tokens)
 
 
+@pytest.mark.skipif(len(jax.devices()) < 2,
+                    reason="needs >=2 devices "
+                           "(XLA_FLAGS=--xla_force_host_platform_device_count=2)")
+def test_device_replicas_match_and_decode_on_their_own_device():
+    """devices=[d0, d1]: each replica's MCT match and decode steps run on
+    its own device (the rule table and queries follow the replica), and
+    completions still equal the sync run."""
+    from repro.core.compiler import compile_rules
+    from repro.core.engine import ErbiumEngine
+    from repro.core.rules import generate_rules
+    devs = jax.devices()[:2]
+    rs = generate_rules(300, version=2, seed=4)
+    engine = ErbiumEngine(compile_rules(rs), tile_b=32, tile_r=128)
+    srv = build(ServeConfig(model="llama3.2-3b", max_seq=32, devices=devs,
+                            rule_filter=engine, target_batch=4))
+    reqs = SyntheticWorkload(vocab=srv.engine.cfg.vocab, prompt_len=5,
+                             max_new_tokens=3, n_mct_queries=8, ruleset=rs,
+                             seed=2).build(16)
+    for r in reqs[::3]:
+        r.connect_minutes[0] = -1          # below any MCT: filtered
+    seen = []
+    match, decode = engine.match, srv.engine._decode
+
+    def logged_match(encoded, device=None):
+        out = match(encoded, device=device)
+        seen.append(("match", device, out[0].devices()))
+        return out
+
+    def logged_decode(*args):
+        logits, cache = decode(*args)
+        seen.append(("decode", None, logits.devices()))
+        return logits, cache
+
+    engine.match, srv.engine._decode = logged_match, logged_decode
+    pipelined = srv.serve(reqs, mode="pipelined")
+    on = {d for kind, dev, ds in seen if kind == "match" for d in ds}
+    assert on == set(devs)
+    assert all(ds == {dev} for kind, dev, ds in seen if kind == "match")
+    assert {d for kind, _, ds in seen if kind == "decode"
+            for d in ds} == set(devs)
+    sync = {c.rid: c for c in srv.serve(reqs, mode="sync")}
+    assert {c.rid for c in pipelined} == set(sync) \
+        == {r.rid for i, r in enumerate(reqs) if i % 3}
+    for c in pipelined:
+        np.testing.assert_array_equal(sync[c.rid].tokens, c.tokens)
+
+
 # ---------------------------------------------------------------------------
 # hit-aware routing: cache-ownership affinity with a straggler guard
 # ---------------------------------------------------------------------------
