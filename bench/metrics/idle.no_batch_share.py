@@ -1,0 +1,9 @@
+"""Share of the traced window in which device 0 ran nothing and no
+``mct.*`` span was open, so no host executor worker held a batch
+(``bench/idle_split.py``), in %."""
+from bench import idle_split
+
+
+def read(run):
+    parts = idle_split.shares(run.trace)
+    return None if parts is None else parts["no_batch"]

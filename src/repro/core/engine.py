@@ -8,13 +8,22 @@ accelerator axis (independent engines with their own table replica).
 Rule hot-reload (the paper's 500 µs NFA update) swaps the device table
 buffers without touching the compiled matcher.
 
+A jitted match call that compiles runs inside a ``mct.compile`` profiler
+span, and its time is what ``last_compile_s`` reports to the caller's
+thread. Whether a call compiles is read from the jit's own cache key (the
+argument shapes, dtypes and placements, the table's structure, the static
+arguments): a key that no call has returned from yet compiles, or waits on
+the worker that compiles it.
+
 CPU baselines (paper §5.2): ``cpu_match_numpy`` — the optimised vectorised
 implementation standing in for the refactored C++ MCT v2 module; and
 ``cpu_match_python`` — a per-query scalar loop (the pre-optimisation shape).
 """
 from __future__ import annotations
 
+import threading
 import time
+from contextlib import nullcontext
 from typing import Dict, Optional, Sequence, Tuple
 
 import jax
@@ -25,6 +34,19 @@ from repro.core.compiler import CompiledRuleTable, compile_rules
 from repro.core.encoder import encode, queries_to_arrays
 from repro.core.rules import RuleSet
 from repro.kernels import ops
+from repro.serve.trace import EXECUTOR_SPANS
+
+# jit cache keys of the match calls that have returned, process-wide as the
+# jit cache is; a key joins only once its call has returned, so workers
+# racing on one new key are all labelled as compiling
+_returned: set = set()
+
+
+def _jit_key(fn, args, static) -> tuple:
+    leaves, tree = jax.tree_util.tree_flatten(args)
+    return (fn, tree, static, tuple(
+        (x.shape, x.dtype, x.sharding) if isinstance(x, jax.Array)
+        else type(x) for x in leaves))
 
 
 class ErbiumEngine:
@@ -42,6 +64,7 @@ class ErbiumEngine:
         # replica pinned to device d never matches on the default device
         self._dev_dt: Dict[object, ops.DeviceRuleTable] = {}
         self.reload_us: Optional[float] = None
+        self._calls = threading.local()
 
     # -- online path ---------------------------------------------------------
     def encode(self, fields: Dict[str, np.ndarray]) -> np.ndarray:
@@ -68,10 +91,26 @@ class ErbiumEngine:
         else:
             q = jax.device_put(np.asarray(encoded, np.int32), device)
         if self.partitioned:
-            return ops.match_rules_partitioned(q, dt)
-        return ops.match_rules(q, dt, tile_b=self.tile_b,
-                               tile_r=self.tile_r, backend=self.backend,
-                               n_engines=self.n_engines)
+            fn, static = ops.match_rules_partitioned, {}
+        else:
+            fn, static = ops.match_rules, dict(
+                tile_b=self.tile_b, tile_r=self.tile_r,
+                backend=self.backend, n_engines=self.n_engines)
+        key = _jit_key(fn, (q, dt), tuple(static.items()))
+        compiles = key not in _returned
+        t0 = time.perf_counter()
+        with (jax.profiler.TraceAnnotation(EXECUTOR_SPANS["compile"])
+              if compiles else nullcontext()):
+            out = fn(q, dt, **static)
+        self._calls.compile_s = time.perf_counter() - t0 if compiles else 0.0
+        if compiles:
+            _returned.add(key)
+        return out
+
+    def last_compile_s(self) -> float:
+        """Seconds the calling thread's last ``match`` spent in a jitted
+        call that compiled; 0 if it did not compile."""
+        return getattr(self._calls, "compile_s", 0.0)
 
     def encode_queries_host(self, queries: Sequence[Dict[str, int]]
                             ) -> np.ndarray:

@@ -7,10 +7,16 @@ Every stage is timed (paper Fig. 6 decomposition):
 
   queue -> encode -> dispatch (host->device) -> kernel -> collect
 
-On this CPU-only container the host<->device hop is process-internal; the
-stage structure and relative scaling with batch size reproduce the paper's
-phenomena (transfer/encode dominance at small/large batches respectively),
-and the measured stage costs calibrate the deployment simulator (Figs 7-11).
+``StageTimes`` stamps each stage on the host clock; ``compile_us`` is the
+match call's time when it compiled, kept out of ``kernel_us``. Each stage
+is also a ``jax.profiler`` span on the device trace's clock (names in
+``repro.serve.trace.EXECUTOR_STAGES``): ``mct.execute`` over a worker's
+whole batch (args ``checks``, ``worker``; queue wait is outside it), and
+inside it ``mct.encode``, ``mct.dispatch`` (host-to-device copy),
+``mct.device_execute`` (the match until its results are ready, holding
+``mct.compile`` where the call compiled) and ``mct.collect`` (the
+device-to-host copies). The spans cost about a microsecond each with no
+profiler running.
 """
 from __future__ import annotations
 
@@ -28,6 +34,9 @@ import numpy as np
 from repro.core.aggregator import Batch
 from repro.core.encoder import queries_to_arrays
 from repro.core.engine import ErbiumEngine
+from repro.serve.trace import EXECUTOR_SPANS
+
+_ann = jax.profiler.TraceAnnotation
 
 
 @dataclass
@@ -38,11 +47,12 @@ class StageTimes:
     kernel_us: float = 0.0
     collect_us: float = 0.0
     batch: int = 0
+    compile_us: float = 0.0
 
     @property
     def total_us(self) -> float:
         return (self.queue_us + self.encode_us + self.dispatch_us +
-                self.kernel_us + self.collect_us)
+                self.compile_us + self.kernel_us + self.collect_us)
 
 
 @dataclass
@@ -64,6 +74,7 @@ class MCTWrapper:
         self._threads: List[threading.Thread] = []
         self._stop = threading.Event()
         self._rr = 0
+        self._local = threading.local()    # .worker: this thread's index
 
     # -- lifecycle -----------------------------------------------------------
     def start(self):
@@ -98,6 +109,7 @@ class MCTWrapper:
 
     # -- internals ------------------------------------------------------------
     def _worker_loop(self, wi: int):
+        self._local.worker = wi
         while not self._stop.is_set():
             item = self._in.get()
             if item is None:
@@ -111,28 +123,31 @@ class MCTWrapper:
         eng = self.engines[eng_idx]
         t0 = time.perf_counter()
         st.queue_us = (t0 - t_in) * 1e6
+        with _ann(EXECUTOR_SPANS["execute"], checks=st.batch,
+                  worker=getattr(self._local, "worker", -1)):
+            with _ann(EXECUTOR_SPANS["encode"]):
+                fields = queries_to_arrays(batch.queries)
+                enc = eng.encode(fields)
+            t1 = time.perf_counter()
+            st.encode_us = (t1 - t0) * 1e6
 
-        fields = queries_to_arrays(batch.queries)
-        enc = eng.encode(fields)
-        t1 = time.perf_counter()
-        st.encode_us = (t1 - t0) * 1e6
+            with _ann(EXECUTOR_SPANS["dispatch"]):
+                dev = jax.device_put(jnp.asarray(enc, jnp.int32))
+                dev.block_until_ready()
+            t2 = time.perf_counter()
+            st.dispatch_us = (t2 - t1) * 1e6
 
-        dev = jax.device_put(jnp.asarray(enc, jnp.int32))
-        dev.block_until_ready()
-        t2 = time.perf_counter()
-        st.dispatch_us = (t2 - t1) * 1e6
+            with _ann(EXECUTOR_SPANS["device_execute"]):
+                dec, w, rid = eng.match(dev)
+                jax.block_until_ready((dec, w, rid))
+            t3 = time.perf_counter()
+            st.compile_us = eng.last_compile_s() * 1e6
+            st.kernel_us = (t3 - t2) * 1e6 - st.compile_us
 
-        dec, w, rid = eng.match(dev)
-        jax.block_until_ready((dec, w, rid))
-        t3 = time.perf_counter()
-        st.kernel_us = (t3 - t2) * 1e6
-
-        dec_h = np.asarray(dec)
-        w_h = np.asarray(w)
-        # partition results back to TSs (collect)
-        _ = dec_h.sum()
-        t4 = time.perf_counter()
-        st.collect_us = (t4 - t3) * 1e6
+            with _ann(EXECUTOR_SPANS["collect"]):
+                dec_h = np.asarray(dec)
+                w_h = np.asarray(w)
+            st.collect_us = (time.perf_counter() - t3) * 1e6
         return MCTResult(uid=batch.uid, decisions=dec_h, weights=w_h,
                          times=st)
 
@@ -153,6 +168,7 @@ def measure_stage_times(engine: ErbiumEngine, make_batch, batch_sizes,
             encode_us=float(np.median([r.encode_us for r in runs])),
             dispatch_us=float(np.median([r.dispatch_us for r in runs])),
             kernel_us=float(np.median([r.kernel_us for r in runs])),
-            collect_us=float(np.median([r.collect_us for r in runs])))
+            collect_us=float(np.median([r.collect_us for r in runs])),
+            compile_us=float(np.median([r.compile_us for r in runs])))
         out.append(med)
     return out

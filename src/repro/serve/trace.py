@@ -13,7 +13,9 @@ per-request timeline those aggregates are computed from:
 
 plus ``controller`` events from the capacity subsystem, so batch-target
 doubling and replica parking are visible on the same timeline as the
-requests they affect.
+requests they affect. The MCT host executor's stages (``EXECUTOR_STAGES``)
+share this vocabulary but are profiler spans, always emitted, on the
+device trace's clock.
 
 Design rules:
 
@@ -57,6 +59,14 @@ LIFECYCLE_STAGES = (
     "reject", "shed", "drop", "follower_drop", "negative_drop",
     "cache_store", "controller",
 )
+
+# the MCT host executor's stages (``core/wrapper.MCTWrapper`` and
+# ``core/engine.ErbiumEngine.match``), emitted as ``jax.profiler`` spans
+# named ``mct.<stage>`` on the profiler's clock, beside the device's
+# programs; a stage shared with the lifecycle above is spelled as there
+EXECUTOR_STAGES = ("execute", "encode", "dispatch", "device_execute",
+                   "compile", "collect")
+EXECUTOR_SPANS = {s: "mct." + s for s in EXECUTOR_STAGES}
 
 
 @dataclass
