@@ -10,7 +10,9 @@ and a live ``Server.session()``; one without is the MCT engine behind
 the program's host executor, ``MCTWrapper``, which takes one batch of
 checks per user query. The window offers the traffic for ``seconds``:
 an open loop submits each user query when it is due, a closed loop
-submits a client's next query when its last one is answered.
+submits a client's next query when its last one is answered (the MCT
+wrapper's clients wrap round their pool, so a fast program never runs
+dry).
 Compiles inside the window neither read nor write the persistent cache,
 so every run pays the same for them; they are counted. After the window
 the session drains, the device's peak memory is read, the program's state
@@ -18,6 +20,7 @@ is freed, and what the window produced is compared with the reference.
 """
 from __future__ import annotations
 
+import dataclasses
 import gc
 import importlib.util
 import json
@@ -109,7 +112,8 @@ class Run:
     rejected: List[int] = field(default_factory=list)
     lateness_s: List[float] = field(default_factory=list)
     completions: Dict[int, object] = field(default_factory=dict)
-    query_of: Dict[int, int] = field(default_factory=dict)   # rid -> uid
+    # submission id -> index of the query it carries in traffic.queries
+    query_of: Dict[int, int] = field(default_factory=dict)
     report: object = None
     taps: object = None
     compiles: int = 0
@@ -120,6 +124,7 @@ class Run:
     drain_end: float = 0.0
     gaps: Optional[dict] = None           # per-token logit gaps, checked
     answers: Dict[int, object] = field(default_factory=dict)  # wrapper's
+    checks_distinct: int = 0              # rows the reference read, checked
 
     @property
     def t1(self) -> float:
@@ -284,25 +289,35 @@ def _drive(run: Run, sess, reqs: list) -> None:
 def _drive_wrapper(run: Run, wrapper, batches: list,
                    drain_s: float = 60.0) -> None:
     """Offer the traffic to the MCT wrapper for ``run.seconds``, then wait
-    for every answer, at most ``drain_s`` past the close."""
+    for every answer, at most ``drain_s`` past the close. The closed
+    loop's clients wrap round the pool until the window closes:
+    submission ``k`` repeats ``batches[k % len(batches)]`` under an id of
+    its own, its query's uid plus the lap times the number of queries, so
+    ids of the first lap are the uids. A batch's uid is the index of its
+    query in ``run.traffic.queries``, which ``query_of`` records."""
     import queue
     clock = time.perf_counter
     lock = threading.Lock()
     closed = run.mix["loop"] == "closed"
+    qs = run.traffic.queries
+    n_q = len(qs)
+    assert all(qs[b.uid].uid == b.uid for b in batches)
     state = {"next": 0, "open": True, "out": 0}
 
-    def submit(b, due):
-        run.due[b.uid] = due
-        run.query_of[b.uid] = b.uid
+    def submit(b, due, lap=0):
+        rid = b.uid + lap * n_q
+        run.due[rid] = due
+        run.query_of[rid] = b.uid
         state["out"] += 1
-        wrapper.submit(b)
+        wrapper.submit(dataclasses.replace(b, uid=rid) if lap else b)
 
     def submit_next() -> None:
         with lock:
-            i = state["next"]
-            if state["open"] and i < len(batches):
-                state["next"] = i + 1
-                submit(batches[i], clock())
+            k = state["next"]
+            if state["open"] and batches:
+                state["next"] = k + 1
+                lap, i = divmod(k, len(batches))
+                submit(batches[i], clock(), lap)
 
     def collect() -> None:
         while True:
@@ -351,11 +366,13 @@ def _drive_wrapper(run: Run, wrapper, batches: list,
 def check_answers(run: Run, rules, answers: Optional[dict] = None
                   ) -> Dict[str, dict]:
     """Every number compared for the MCT wrapper's answers, each with its
-    limit: every accepted user query answered, one answer per check, and
-    each answer exactly the reference's. ``answers`` (uid -> (decision,
-    weight)) puts another matcher's answers in the program's place."""
+    limit: every accepted submission answered, one answer per check, and
+    each answer exactly the reference's. ``answers`` (submission id ->
+    (decision, weight)) puts another matcher's answers in the program's
+    place."""
     tr = run.traffic
-    if answers is None:
+    own = answers is None                  # the program's own answers
+    if own:
         answers = {u: (np.asarray(r.decisions), np.asarray(r.weights))
                    for u, r in run.answers.items()}
     accepted = [u for u in run.due if u not in run.rejected]
@@ -372,8 +389,9 @@ def check_answers(run: Run, rules, answers: Optional[dict] = None
     out["answers_missing"] = {"value": off, "limit": 0}
     z = [np.zeros(0, np.int64)]
     rows, dec, wgt = (np.concatenate(a + z) for a in (rows, dec, wgt))
-    got = mct.compare_answers(rules, tr.vals[rows.astype(np.int64)],
-                              dec, wgt)
+    got = mct.compare_answers(rules, tr.vals, rows, dec, wgt)
+    if own:
+        run.checks_distinct = got["distinct"]
     out["mct_checks_compared"] = {"value": got["checks"], "limit": 1,
                                   "at_least": True}
     out["mct_mismatches"] = {"value": got["mismatches"], "limit": 0}
@@ -382,13 +400,15 @@ def check_answers(run: Run, rules, answers: Optional[dict] = None
 
 def control_answers(run: Run, rules) -> dict:
     """The control's answers to the same checks: the first matching rule
-    (``mct.first_match_answers``) in place of the most precise."""
+    (``mct.first_match_answers``) in place of the most precise, worked out
+    once per distinct check."""
     tr = run.traffic
     qs = [(u, tr.queries[run.query_of[u]]) for u in run.answers]
     rows = np.concatenate([np.arange(q.first_check, q.first_check +
                                      q.n_checks) for _, q in qs] +
                           [np.zeros(0, np.int64)]).astype(np.int64)
-    dec, wgt = mct.first_match_answers(rules, tr.vals[rows])
+    (dec, wgt), _ = mct.per_distinct(mct.first_match_answers, rules,
+                                     tr.vals, rows)
     ends = np.cumsum([q.n_checks for _, q in qs])[:-1]
     return {u: dw for (u, _), dw in zip(
         qs, zip(np.split(dec, ends), np.split(wgt, ends)))}
@@ -612,6 +632,7 @@ def run_cell(root: Path, cell_name: str, seed: int, seconds: float,
         server.stop()
     del server
     gc.collect()
+    t_check = time.perf_counter()
     if serves_lm:
         checks = check_outputs(run, rules, params, control=control)
         ctrl = control_checks(run, checks) if control else None
@@ -619,6 +640,7 @@ def run_cell(root: Path, cell_name: str, seed: int, seconds: float,
         checks = check_answers(run, rules)
         ctrl = check_answers(run, rules, control_answers(run, rules)) \
             if control else None
+    check_s = time.perf_counter() - t_check
     correct = is_correct(checks)
 
     # -- metrics ---------------------------------------------------------------
@@ -648,7 +670,8 @@ def run_cell(root: Path, cell_name: str, seed: int, seconds: float,
     result["_log"] = {
         "setup_s": run.setup_s, "queries": n_q, "checks": tr.n_checks,
         "answered": len(run.verdict), "rejected": len(run.rejected),
-        "window_compiles": run.compiles,
+        "submissions": len(run.due),
+        "window_compiles": run.compiles, "check_s": check_s,
         "generator_late_ms_p50": float(np.percentile(late, 50) * 1e3),
         "generator_late_ms_max": float(late.max() * 1e3)}
     if serves_lm:
@@ -656,6 +679,10 @@ def run_cell(root: Path, cell_name: str, seed: int, seconds: float,
             gap=gap_stats(run.gaps["program"]),
             control_gap=gap_stats(run.gaps["control"]),
             worst_token=run.gaps["worst"])
+    else:
+        result["_log"].update(             # completed wraps of the pool
+            laps=max(len(run.due) - 1, 0) // max(len(batches), 1),
+            checks_distinct=run.checks_distinct)
     return result
 
 

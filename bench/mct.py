@@ -222,22 +222,34 @@ def best_weights(rules: Rules, vals: np.ndarray) -> np.ndarray:
     return dense_pass(rules, vals)[0]
 
 
-def compare_answers(rules: Rules, vals: np.ndarray, decision: np.ndarray,
-                    weight: np.ndarray) -> Dict[str, int]:
-    """Mismatches between the (decision, weight) answered per check and
-    the reference. An answer is right when its weight is the best of any
-    matching rule and a rule of that weight carries its decision, or,
-    where no rule matches, when both are negative."""
-    best, carried, _ = dense_pass(rules, vals)
+def compare_answers(rules: Rules, vals: np.ndarray, rows: np.ndarray,
+                    decision: np.ndarray, weight: np.ndarray
+                    ) -> Dict[str, int]:
+    """Mismatches between the (decision, weight) answered for each check
+    ``vals[rows[i]]`` and the reference, which runs once per distinct
+    row however often its check was answered. An answer is right when
+    its weight is the best of any matching rule and a rule of that weight
+    carries its decision, or, where no rule matches, when both are
+    negative."""
+    (best, carried, _), distinct = per_distinct(dense_pass, rules, vals,
+                                                rows)
     none = best < 0
     kind = np.searchsorted(DECISIONS, decision)
     known = np.isin(decision, DECISIONS)
     ok_rule = known & (weight == best) & carried[
-        np.arange(len(vals)), np.minimum(kind, len(DECISIONS) - 1)]
+        np.arange(len(rows)), np.minimum(kind, len(DECISIONS) - 1)]
     ok_none = (decision < 0) & (weight < 0)
     bad = ~np.where(none, ok_none, ok_rule)
-    return {"checks": int(len(vals)), "mismatches": int(bad.sum()),
-            "matched": int((~none).sum())}
+    return {"checks": int(len(rows)), "mismatches": int(bad.sum()),
+            "matched": int((~none).sum()), "distinct": distinct}
+
+
+def per_distinct(fn, rules: Rules, vals: np.ndarray, rows: np.ndarray):
+    """``fn(rules, vals[rows])`` worked out once per distinct row: each
+    array ``fn`` returns, indexed back to ``rows``, and the number of
+    distinct rows."""
+    uniq, inv = np.unique(rows, return_inverse=True)
+    return tuple(a[inv] for a in fn(rules, vals[uniq])), len(uniq)
 
 
 def first_match_answers(rules: Rules, vals: np.ndarray):

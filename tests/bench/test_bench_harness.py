@@ -181,6 +181,7 @@ def test_wrapper_cell_reports_its_metrics(tmp_path, monkeypatch):
     m = res["metrics"]
     assert m["window.compiles"]["value"] >= 1
     assert m["encode.us_per_check"]["value"] > 0
+    assert 0 <= m["window.repeat_share"]["value"] < 100
     # no TPU here: the device-trace metrics find nothing and are left out
     assert "rule_match_roofline" not in m
     e2e = tiny_bench.run(tmp_path, cell=tiny_bench.MCT_CELL)["metrics"]
