@@ -10,7 +10,6 @@ from __future__ import annotations
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import emit, rule_system
@@ -39,14 +38,14 @@ def run():
             continue
         encs = [encode_queries(table, b.queries) for b in batches]
         for e in encs:  # warm the jit caches per shape
-            jax.block_until_ready(eng.match(jnp.asarray(e, jnp.int32)))
+            jax.block_until_ready(eng.match(e))
         t0 = time.perf_counter()
         for e in encs:
             cpu_match_numpy(table, e)
         t_cpu = (time.perf_counter() - t0) * 1e6
         t0 = time.perf_counter()
         for e in encs:
-            jax.block_until_ready(eng.match(jnp.asarray(e, jnp.int32)))
+            jax.block_until_ready(eng.match(e))
         t_acc = (time.perf_counter() - t0) * 1e6
         n = uq.n_mct
         rows.append((n, t_cpu, t_acc, len(batches)))

@@ -7,16 +7,18 @@ Every stage is timed (paper Fig. 6 decomposition):
 
   queue -> encode -> dispatch (host->device) -> kernel -> collect
 
-``StageTimes`` stamps each stage on the host clock; ``compile_us`` is the
-match call's time when it compiled, kept out of ``kernel_us``. Each stage
-is also a ``jax.profiler`` span on the device trace's clock (names in
+``StageTimes`` stamps each stage on the host clock; ``dispatch_us`` (the
+engine's padding of the rows and their host-to-device copy) and
+``compile_us`` (the match call's time when it compiled) are read from the
+engine and kept out of ``kernel_us``. Each stage is also a
+``jax.profiler`` span on the device trace's clock (names in
 ``repro.serve.trace.EXECUTOR_STAGES``): ``mct.execute`` over a worker's
 whole batch (args ``checks``, ``worker``; queue wait is outside it), and
-inside it ``mct.encode``, ``mct.dispatch`` (host-to-device copy),
-``mct.device_execute`` (the match until its results are ready, holding
-``mct.compile`` where the call compiled) and ``mct.collect`` (the
-device-to-host copies). The spans cost about a microsecond each with no
-profiler running.
+inside it ``mct.encode``, ``mct.device_execute`` (the match until its
+results are ready, holding the engine's ``mct.dispatch`` and, where the
+call compiled, ``mct.compile``) and ``mct.collect`` (the device-to-host
+copies). The spans cost about a microsecond each with no profiler
+running.
 """
 from __future__ import annotations
 
@@ -28,7 +30,6 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core.aggregator import Batch
@@ -131,18 +132,13 @@ class MCTWrapper:
             t1 = time.perf_counter()
             st.encode_us = (t1 - t0) * 1e6
 
-            with _ann(EXECUTOR_SPANS["dispatch"]):
-                dev = jax.device_put(jnp.asarray(enc, jnp.int32))
-                dev.block_until_ready()
-            t2 = time.perf_counter()
-            st.dispatch_us = (t2 - t1) * 1e6
-
             with _ann(EXECUTOR_SPANS["device_execute"]):
-                dec, w, rid = eng.match(dev)
+                dec, w, rid = eng.match(enc)
                 jax.block_until_ready((dec, w, rid))
             t3 = time.perf_counter()
+            st.dispatch_us = eng.last_dispatch_s() * 1e6
             st.compile_us = eng.last_compile_s() * 1e6
-            st.kernel_us = (t3 - t2) * 1e6 - st.compile_us
+            st.kernel_us = (t3 - t1) * 1e6 - st.dispatch_us - st.compile_us
 
             with _ann(EXECUTOR_SPANS["collect"]):
                 dec_h = np.asarray(dec)

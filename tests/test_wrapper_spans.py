@@ -37,21 +37,26 @@ def test_stage_spans_and_compile_labels(tmp_path):
     rs, e1 = _engine(5)
     _, e2 = _engine(6)
     assert e1.dt.mins_t.shape == e2.dt.mins_t.shape
-    qs = generate_queries(rs, 16, seed=1)
+    qs = generate_queries(rs, 90, seed=1)
     wrap = MCTWrapper([e1, e2], n_workers=2)
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     wrap.start()
-    times = []
+    results = []
     try:
         with jax.profiler.trace(str(tmp_path), profiler_options=opts):
-            for uid, n in enumerate([3, 5, 3, 7]):
+            # rows are padded to a multiple of TILE_B: 3 and 45 and 85
+            # checks fall in three buckets, the second 3 in the first's
+            for uid, n in enumerate([3, 45, 3, 85]):
                 wrap.submit(_batch(uid, qs, n))
-                times += [r.times for r in wrap.drain(1)]
+                results += wrap.drain(1)
             # the second engine's table has the same shape: no compile
-            times.append(wrap.process(_batch(9, qs, 3), engine_idx=1).times)
+            results.append(wrap.process(_batch(9, qs, 3), engine_idx=1))
     finally:
         wrap.stop()
+    for r, n in zip(results, [3, 45, 3, 85, 3]):
+        assert len(r.decisions) == len(r.weights) == r.times.batch == n
+    times = [r.times for r in results]
     tr = trace_reduce.load(str(tmp_path))
     spans = {s: trace_reduce.host_spans(tr, name)
              for s, name in EXECUTOR_SPANS.items()}
@@ -70,7 +75,7 @@ def test_stage_spans_and_compile_labels(tmp_path):
     assert [t.compile_us > 0 for t in times] == [True, True, False, True,
                                                 False]
     for t in times:
-        assert t.kernel_us > 0
+        assert t.kernel_us > 0 and t.dispatch_us > 0
         assert t.total_us == pytest.approx(
             t.queue_us + t.encode_us + t.dispatch_us + t.compile_us +
             t.kernel_us + t.collect_us)
@@ -78,7 +83,8 @@ def test_stage_spans_and_compile_labels(tmp_path):
 
 def test_workers_racing_on_a_new_count_are_both_labelled():
     rs, eng = _engine(7)
-    enc = eng.encode_queries_host(generate_queries(rs, 16, seed=2)[:11])
+    # 130 rows: a bucket (160) no other test here reaches
+    enc = eng.encode_queries_host(generate_queries(rs, 130, seed=2))
     start = threading.Barrier(2)
     took = []
 
