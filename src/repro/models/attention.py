@@ -221,11 +221,14 @@ def qblock_attention(q, k, v, *, causal: bool, window: int = 0,
     return out.astype(q.dtype)
 
 
-def attention_scores_decode(q, k_cache, v_cache, *, pos, window: int = 0):
+def attention_scores_decode(q, k_cache, v_cache, *, pos, window: int = 0,
+                            start=None):
     """Single-token attention against a cache.
 
     q: (B, 1, K, G, d); k_cache/v_cache: (B, S, K, d); pos: scalar int —
     number of valid cache entries (the new token's absolute position + 1).
+    start: optional (B,) int — each row's first valid cache entry (a
+    left-padded prompt's first token); entries before it are masked.
 
     Mixed precision via preferred_element_type (bf16 inputs, fp32
     accumulation) — casting the cache would let XLA hoist an fp32 convert of
@@ -237,11 +240,15 @@ def attention_scores_decode(q, k_cache, v_cache, *, pos, window: int = 0):
     scale = 1.0 / np.sqrt(d)
     s = jnp.einsum("bqkgd,bskd->bkgqs", q.astype(k_cache.dtype), k_cache,
                    preferred_element_type=jnp.float32) * scale
-    ids = jnp.arange(S)
-    valid = ids[None, :] < pos
+    ids = jnp.arange(S)[None, :]
+    valid = ids < pos
     if window > 0:
-        valid &= ids[None, :] > pos - 1 - window
-    s = jnp.where(valid[None, None, None], s, NEG_INF)
+        valid &= ids > pos - 1 - window
+    if start is not None:
+        valid = valid & (ids >= start[:, None])             # (B, S)
+    else:
+        valid = jnp.broadcast_to(valid, (B, S))
+    s = jnp.where(valid[:, None, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bkgqs,bskd->bqkgd", p.astype(v_cache.dtype), v_cache,
                      preferred_element_type=jnp.float32)
@@ -304,9 +311,13 @@ def attn_forward(params, x, *, n_heads, n_kv_heads, head_dim,
 
 
 def attn_decode(params, x, cache_k, cache_v, *, pos, n_heads, n_kv_heads,
-                head_dim, rope_theta, window: int = 0, shard=None):
+                head_dim, rope_theta, window: int = 0, shard=None,
+                start=None):
     """One-token decode. x: (B, 1, D); cache: (B, S, K, hd). pos: scalar —
-    index where the new token is written. Returns (out, cache_k, cache_v)."""
+    index where the new token is written. start: optional (B,) — each
+    row's first cache index; keys before it are masked and q/k rotate at
+    the row-relative position ``pos - start``. Returns (out, cache_k,
+    cache_v)."""
     B, _, D = x.shape
     q = x @ params["wq"].astype(x.dtype)
     k = x @ params["wk"].astype(x.dtype)
@@ -315,7 +326,8 @@ def attn_decode(params, x, cache_k, cache_v, *, pos, n_heads, n_kv_heads,
     k = _split_kv(k, n_kv_heads, head_dim)
     v = _split_kv(v, n_kv_heads, head_dim)
     if rope_theta is not None:
-        p = jnp.full((1,), pos)
+        p = jnp.full((1,), pos) if start is None \
+            else (pos - start)[:, None]                     # (B, 1)
         q = apply_rope(q, p, rope_theta)
         k = apply_rope(k, p, rope_theta)
     cache_k = jax.lax.dynamic_update_slice_in_dim(cache_k, k.astype(cache_k.dtype), pos, axis=1)
@@ -323,7 +335,7 @@ def attn_decode(params, x, cache_k, cache_v, *, pos, n_heads, n_kv_heads,
     if shard is not None:
         cache_k, cache_v = shard(cache_k), shard(cache_v)
     out = attention_scores_decode(q, cache_k, cache_v, pos=pos + 1,
-                                  window=window)
+                                  window=window, start=start)
     out = out.reshape(B, 1, n_heads * head_dim)
     return out @ params["wo"].astype(x.dtype), cache_k, cache_v
 

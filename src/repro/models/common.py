@@ -69,9 +69,8 @@ def apply_rope(x, positions, theta):
     d = x.shape[-1]
     freqs = rope_freqs(d, theta)                      # (d/2,)
     angles = positions.astype(jnp.float32)[..., None] * freqs  # (..., S, d/2)
-    # reshape angles to broadcast over head dims between S and d
-    extra = x.ndim - angles.ndim - 1
-    for _ in range(extra):
+    # reshape angles to broadcast over the head dims between S and d
+    for _ in range(x.ndim - 3):
         angles = angles[..., None, :]
     sin, cos = jnp.sin(angles), jnp.cos(angles)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)

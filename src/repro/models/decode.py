@@ -4,6 +4,15 @@
 functionally (callers donate it for in-place updates). ``cache_struct``
 returns the ShapeDtypeStruct tree used both to allocate zeros (serving) and
 as abstract dry-run inputs (no allocation).
+
+Mixed prompt lengths: a batch's prompts are left-padded to a common end
+and the uniform-attention and hybrid caches carry each row's ``start``
+(its first real position, (B,) int32). A step at ``pos < start[b]`` is a
+pad step for row b: attention masks cache entries before ``start`` and
+rotates at the row-relative position ``pos - start``, and the Mamba conv
+buffer and state stay as they were, so each row's logits are those of
+its prompt served alone. The xLSTM and VLM caches carry no ``start``:
+those families serve one prompt length per batch.
 """
 from __future__ import annotations
 
@@ -60,15 +69,26 @@ def cache_struct(cfg: ModelConfig, batch: int, seq_len: int) -> Dict[str, Any]:
             c["mamba_conv"] = sds((n, B, W - 1, di), f32)
             c["mamba_h"] = sds((n, B, di, N), f32)
         runs.append(c)
-    return {"runs": runs}
+    return {"runs": runs, "start": sds((B,), jnp.int32)}
 
 
-def init_cache(cfg: ModelConfig, batch: int, seq_len: int):
+def init_cache(cfg: ModelConfig, batch: int, seq_len: int, start=None):
+    """Zeroed cache; ``start`` (batch,) sets each row's first position
+    (default 0 for every row)."""
     st = cache_struct(cfg, batch, seq_len)
     z = jax.tree_util.tree_map(lambda s: jnp.zeros(s.shape, s.dtype), st)
     if cfg.family == "ssm":
         z["m_m"] = z["m_m"] - 1e30
         z["s_m"] = z["s_m"] - 1e30
+    if start is not None:
+        start = np.asarray(start, np.int32)
+        if "start" in z:
+            z["start"] = jnp.asarray(start)
+        elif start.any():
+            raise ValueError(
+                f"{cfg.arch}: the {cfg.family} decode cache cannot mask "
+                f"per-row prompt starts; serve prompts of one length per "
+                f"batch")
     return z
 
 
@@ -85,18 +105,20 @@ def decode_step(params, cache, token, pos, cfg: ModelConfig, ctx=None
     elif cfg.cross_attn_every:
         x, cache = _vlm_decode(params, cache, x, pos, cfg, ctx)
     else:
+        start = cache["start"]
         new_runs = []
         for run_p, run_c, (n, w, th) in zip(params["blocks"], cache["runs"],
                                             attn_runs(cfg)):
             def body(xc, inp, _w=w, _th=th):
                 blk, c = inp
                 y, c2 = apply_block(blk, xc, cfg, window=_w, theta=_th,
-                                    ctx=ctx, mode="decode", cache=c, pos=pos)
+                                    ctx=ctx, mode="decode", cache=c, pos=pos,
+                                    start=start)
                 return y, c2
 
             x, c_new = jax.lax.scan(body, x, (run_p, run_c))
             new_runs.append(c_new)
-        cache = {"runs": new_runs}
+        cache = {"runs": new_runs, "start": start}
 
     x = norm_apply(params["norm_f"], x, _norm_kind(cfg), cfg.norm_eps)
     logits = _unembed(params, cfg, x)

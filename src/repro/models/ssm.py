@@ -172,8 +172,12 @@ def mamba_init_state(params, batch: int, dtype=jnp.float32) -> MambaState:
                       h=jnp.zeros((batch, di, N), jnp.float32))
 
 
-def mamba_step(params, x, state: MambaState, *, cfg):
-    """Single-token decode. x: (B, 1, D). Returns (y (B,1,D), new_state)."""
+def mamba_step(params, x, state: MambaState, *, cfg, live=None):
+    """Single-token decode. x: (B, 1, D). Returns (y (B,1,D), new_state).
+
+    live: optional (B,) bool — rows where it is False take a pad step
+    (a left-padded prompt's lead-in): their conv buffer and SSM state
+    come back unchanged."""
     B = x.shape[0]
     xz = x @ params["w_in"].astype(x.dtype)
     u, z = jnp.split(xz, 2, axis=-1)                    # (B, 1, di)
@@ -187,6 +191,10 @@ def mamba_step(params, x, state: MambaState, *, cfg):
     y = y + u_c.astype(jnp.float32) * params["d_skip"][None, None]
     y = y.astype(x.dtype) * jax.nn.silu(z)
     new_state = MambaState(conv=conv_in[:, 1:], h=h)
+    if live is not None:
+        new_state = MambaState(
+            conv=jnp.where(live[:, None, None], new_state.conv, state.conv),
+            h=jnp.where(live[:, None, None], new_state.h, state.h))
     return y @ params["w_out"].astype(x.dtype), new_state
 
 

@@ -98,8 +98,13 @@ def attn_runs(cfg: ModelConfig):
 
 def apply_block(p, x, cfg: ModelConfig, *, window, theta, ctx,
                 positions=None, mode: str = "train",
-                cache: Optional[dict] = None, pos=None):
+                cache: Optional[dict] = None, pos=None, start=None):
     """One block. mode: train|prefill (full-seq) or decode (one token).
+
+    In decode, ``start`` (B,) gives each row's first position (a hybrid
+    block needs it): a row before its start takes a pad step, which
+    attention masks from later steps and which leaves the Mamba state
+    unchanged.
 
     Returns (x, new_cache_entry) where new_cache_entry is None in train mode.
     """
@@ -123,7 +128,7 @@ def apply_block(p, x, cfg: ModelConfig, *, window, theta, ctx,
             p["attn"], h, cache["k"], cache["v"], pos=pos,
             n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
             head_dim=cfg.head_dim, rope_theta=theta, window=window,
-            shard=shard)
+            shard=shard, start=start)
         new_cache["k"], new_cache["v"] = ck, cv
 
     if cfg.parallel_ssm:
@@ -135,7 +140,8 @@ def apply_block(p, x, cfg: ModelConfig, *, window, theta, ctx,
         else:
             st = ssm_mod.MambaState(conv=cache["mamba_conv"],
                                     h=cache["mamba_h"])
-            s_out, st = ssm_mod.mamba_step(p["mamba"], h, st, cfg=cfg.ssm)
+            s_out, st = ssm_mod.mamba_step(
+                p["mamba"], h, st, cfg=cfg.ssm, live=pos >= start)
             new_cache["mamba_conv"], new_cache["mamba_h"] = st.conv, st.h
         a_out = 0.5 * (norm_apply(p["norm_attn_o"], a_out, nk, eps)
                        + norm_apply(p["norm_ssm_o"], s_out, nk, eps))

@@ -27,6 +27,9 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core.aggregator import DeadlineAggregator
 from repro.models.registry import build_model
+from repro.serve.trace import LM_SPANS
+
+_ann = jax.profiler.TraceAnnotation
 
 
 @dataclass
@@ -49,6 +52,10 @@ class Completion:
     batch_size: int
     truncated: bool = False       # hit the max_seq context limit before
                                   # max_new_tokens were produced
+    rows_padded: int = 0          # rows each decode step of its batch ran
+                                  # (the power-of-two bucket)
+    steps: int = 0                # decode steps its batch ran, prompt
+                                  # and generation
 
 
 @dataclass
@@ -56,7 +63,7 @@ class PreparedBatch:
     """Host-side half of a batch: everything the device stage needs,
     assembled without touching the accelerator."""
     requests: List[Request]
-    toks: np.ndarray                      # (B, max_plen) int32
+    toks: np.ndarray                      # (B, max_plen) int32, left-padded
     plens: List[int]
     max_new: int
     mct_encoded: Optional[np.ndarray]     # (Q, C) int32 or None
@@ -92,8 +99,10 @@ class LMServer:
         # batch-size bucketing: pad each batch to the next power of two so
         # the jitted decode step compiles O(log B) variants instead of one
         # per distinct batch size — without it, a deadline-formed stream of
-        # ragged batches is a compile storm. Rows are independent (masked
-        # attention), so padding never changes per-request results.
+        # ragged batches is a compile storm. Rows never mix (attention and
+        # the Mamba state are per row), so pad rows change no request's
+        # result; pad steps of shorter prompts are masked per row (see
+        # ``_run_decode``).
         self.pad_batches = pad_batches
         self._decode = jax.jit(
             lambda p, c, t, pos: self.model.decode_step(p, c, t, pos),
@@ -103,13 +112,16 @@ class LMServer:
     # -- host-side prepare stage ----------------------------------------------
     def prepare_batch(self, requests: Sequence[Request]) -> PreparedBatch:
         """Assemble the token matrix and encode MCT queries — pure host
-        (numpy) work, safe to run while the device executes another batch."""
+        (numpy) work, safe to run while the device executes another batch.
+        Prompts are left-padded with token 0, so every row ends in the
+        last column."""
         rs = list(requests)
         plens = [len(r.tokens) for r in rs]
         max_new = max((r.max_new_tokens for r in rs), default=0)
-        toks = np.zeros((len(rs), max(plens, default=0)), np.int32)
+        width = max(plens, default=0)
+        toks = np.zeros((len(rs), width), np.int32)
         for i, r in enumerate(rs):
-            toks[i, :plens[i]] = r.tokens
+            toks[i, width - plens[i]:] = r.tokens
         mct_encoded, owner = None, []
         if self.rule_filter is not None:
             flat = []
@@ -180,6 +192,15 @@ class LMServer:
     def _run_decode(self, rs: List[Request], toks: np.ndarray,
                     plens: List[int], max_new: int,
                     device=None) -> List[Completion]:
+        """Prompt steps, then generation steps, all through the one jitted
+        decode step. ``toks`` holds the prompts left-padded to a common
+        end, so every row's last prompt token is stepped at ``max_p - 1``
+        and its first at ``start = max_p - plen``. The cache carries each
+        row's ``start``: a step before it is a pad step, which the model
+        masks out of the row's attention and leaves out of its Mamba
+        state, so a row's tokens are those of its prompt served alone.
+        Pad rows (the power-of-two bucket) step token 0 from the first
+        step; rows never mix, so they change no real row's result."""
         t0 = time.perf_counter()
         B = len(rs)
         total = self.max_seq
@@ -191,6 +212,9 @@ class LMServer:
             raise ValueError(
                 f"max_seq={total} too small for the prompt alone "
                 f"(longest prompt: {max_p})")
+        # the filter may have dropped the longest prompts: skip the lead-in
+        # columns no kept row needs
+        toks = toks[:, toks.shape[1] - max_p:]
 
         Bp = B
         if self.pad_batches and B > 1:
@@ -198,44 +222,48 @@ class LMServer:
         if Bp != B:
             toks = np.concatenate(
                 [toks, np.zeros((Bp - B, toks.shape[1]), np.int32)])
+        start = np.zeros(Bp, np.int32)
+        start[:B] = max_p - np.asarray(plens)
+        # generation steps: each feeds the last token, until max_new tokens
+        # or the context limit
+        n_gen = max(0, min(max_new - 1, total - 1 - max_p))
 
         params = self._params_on(device)
+        generated = [[] for _ in range(B)]
         # the cache, step tokens and argmax live on the replica's device
         with jax.default_device(device):
-            cache = self.model.init_cache(Bp, total)
-            # prefill via the decode path, token by token up to each prompt
-            # len (keeps one compiled step; a fused prefill kernel is the
-            # fast path for attention archs, exercised in tests via
-            # model.prefill)
-            generated = [[] for _ in range(B)]
-            last_logits = None
-            for pos in range(max_p):
-                step_tok = jnp.asarray(toks[:, pos:pos + 1])
-                last_logits, cache = self._decode(params, cache, step_tok,
-                                                  jnp.int32(pos))
-            t1 = time.perf_counter()
-
-            cur = np.asarray(jnp.argmax(last_logits[:, -1], axis=-1),
-                             np.int32)
-            for s in range(max_new):
-                for i in range(B):
-                    if s < rs[i].max_new_tokens:
-                        generated[i].append(int(cur[i]))
-                pos = max_p + s
-                if pos >= total - 1 or s == max_new - 1:
-                    break
-                logits, cache = self._decode(params, cache,
-                                             jnp.asarray(cur[:, None]),
-                                             jnp.int32(pos))
+            cache = self.model.init_cache(Bp, total, start=start)
+            # prefill via the decode path, token by token (keeps one
+            # compiled step; a fused prefill kernel is the fast path for
+            # attention archs, exercised in tests via model.prefill)
+            with _ann(LM_SPANS["prefill"], rows=B):
+                for pos in range(max_p):
+                    step_tok = jnp.asarray(toks[:, pos:pos + 1])
+                    logits, cache = self._decode(params, cache, step_tok,
+                                                 jnp.int32(pos))
                 cur = np.asarray(jnp.argmax(logits[:, -1], axis=-1),
                                  np.int32)
-            jax.block_until_ready(cur)
+            t1 = time.perf_counter()
+
+            with _ann(LM_SPANS["generate"], rows=B):
+                for s in range(max_new):
+                    for i in range(B):
+                        if s < rs[i].max_new_tokens:
+                            generated[i].append(int(cur[i]))
+                    if s == n_gen:
+                        break
+                    logits, cache = self._decode(params, cache,
+                                                 jnp.asarray(cur[:, None]),
+                                                 jnp.int32(max_p + s))
+                    cur = np.asarray(jnp.argmax(logits[:, -1], axis=-1),
+                                     np.int32)
             t2 = time.perf_counter()
 
         return [Completion(rid=r.rid, tokens=np.asarray(g, np.int32),
                            prefill_ms=(t1 - t0) * 1e3,
                            decode_ms=(t2 - t1) * 1e3, batch_size=B,
-                           truncated=len(g) < r.max_new_tokens)
+                           truncated=len(g) < r.max_new_tokens,
+                           rows_padded=Bp, steps=max_p + n_gen)
                 for r, g in zip(rs, generated)]
 
     # -- continuous batching front end ----------------------------------------
@@ -253,8 +281,9 @@ class LMServer:
         encoded host-side into ONE kernel input (the paper's aggregation
         lesson); match on ``device``, then drop requests with an
         infeasible connection (connect time < MCT)."""
-        dec, _, _ = self.rule_filter.match(encoded, device=device)
-        dec = np.asarray(dec)
+        with _ann(LM_SPANS["filter"], checks=len(encoded), rows=len(rs)):
+            dec, _, _ = self.rule_filter.match(encoded, device=device)
+            dec = np.asarray(dec)
         feasible = [True] * len(rs)
         pos = {i: 0 for i in range(len(rs))}
         for j, i in enumerate(owner):

@@ -14,8 +14,8 @@ per-request timeline those aggregates are computed from:
 plus ``controller`` events from the capacity subsystem, so batch-target
 doubling and replica parking are visible on the same timeline as the
 requests they affect. The MCT host executor's stages (``EXECUTOR_STAGES``)
-share this vocabulary but are profiler spans, always emitted, on the
-device trace's clock.
+and the LM path's phases (``LM_PHASES``) share this vocabulary but are
+profiler spans, always emitted, on the device trace's clock.
 
 Design rules:
 
@@ -67,6 +67,14 @@ LIFECYCLE_STAGES = (
 EXECUTOR_STAGES = ("execute", "encode", "dispatch", "device_execute",
                    "compile", "collect")
 EXECUTOR_SPANS = {s: "mct." + s for s in EXECUTOR_STAGES}
+
+# the LM serving path's phases (``serve/engine.LMServer``), profiler spans
+# like the executor's: ``lm.filter`` around a batch's MCT match (args
+# ``checks``, ``rows``), ``lm.prefill`` around its prompt steps and
+# ``lm.generate`` around its generation steps (arg ``rows``); a batch's
+# padded rows and steps travel on its ``Completion``s
+LM_PHASES = ("filter", "prefill", "generate")
+LM_SPANS = {s: "lm." + s for s in LM_PHASES}
 
 
 @dataclass

@@ -209,6 +209,10 @@ def cache_shardings(cache_tree, cfg: ModelConfig, ctx: ShardCtx):
     def one(sds):
         shp = sds.shape
         nd = len(shp)
+        # per-row prompt starts: (B,)
+        if nd == 1:
+            return NamedSharding(ctx.mesh,
+                                 P(ctx.maybe(shp[0], ctx.batch_axes)))
         # attention caches: (..., B, S, K, hd)
         if nd >= 4 and shp[-1] == cfg.head_dim and shp[-2] == cfg.n_kv_heads:
             b = ctx.maybe(shp[-4], ctx.batch_axes)
